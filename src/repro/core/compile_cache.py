@@ -30,9 +30,30 @@ into the cache.
 """
 from __future__ import annotations
 
+import os
+import pathlib
 import warnings
 
 import jax
+
+# <checkout>/src/repro/core/compile_cache.py -> <checkout>
+_CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def use_persistent_cache() -> str:
+    """Place JAX's persistent compilation cache; call before the first
+    compile. Returns the directory in use.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is
+    left alone. Otherwise the cache goes to ``<checkout>/.jax_cache``: a
+    fixed path, so every later run from the same checkout finds what an
+    earlier one compiled.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(_CHECKOUT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 class JitCache:

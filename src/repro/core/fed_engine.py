@@ -156,6 +156,24 @@ def _batch_len(stacked) -> int:
     return int(jax.tree_util.tree_leaves(stacked)[0].shape[0])
 
 
+def _varying_like(tree, like):
+    """Type every leaf of ``tree`` as varying over the manual mesh axes
+    that ``like`` varies over (identity outside ``shard_map``).
+
+    A client's scan carry starts from replicated values (the global
+    params, a fresh optimizer state) and ends on values computed from its
+    own batches. Under ``shard_map`` those batches vary over the client
+    axes, and scan needs its carry typed alike on entry and exit.
+    """
+    axes = jax.typeof(like).vma
+
+    def cast(x):
+        missing = tuple(sorted(axes - jax.typeof(x).vma))
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+    return jax.tree_util.tree_map(cast, tree)
+
+
 def _full_iters(stacked_clients):
     """(n,) iteration vector for 'every client runs the whole stack'."""
     n, H = jax.tree_util.tree_leaves(stacked_clients)[0].shape[:2]
@@ -245,7 +263,9 @@ class ClientRun:
             return carry, jnp.where(active, loss, jnp.nan)
 
         H = _batch_len(stacked)
-        init = (params_global, self.opt.init(params_global), state)
+        init = _varying_like(
+            (params_global, self.opt.init(params_global), state),
+            jax.tree_util.tree_leaves(stacked)[0])
         (w_new, _, state_f), losses = jax.lax.scan(
             body, init, (jnp.arange(H, dtype=jnp.int32), stacked))
         if not alg.stateful:
@@ -641,7 +661,7 @@ class ShardedSyncRound(SyncRound):
 
         c, r = self._specs["clients"], self._specs["replicated"]
         out_specs = (r, r, c, c) if self.algorithm.stateful else (r, c)
-        self._sharded_rnd = sh.shard_map(
+        self._sharded_rnd = jax.shard_map(
             shard_fn, mesh=mesh, in_specs=(r, c, c, c, r, r, c),
             out_specs=out_specs)
 

@@ -138,11 +138,17 @@ def make_dataset_for(cfg, *, small: bool = True, seed: int = 0):
 
     small=True  -> HMDB51-like (few samples, noisy; clients' fine-tune data)
     small=False -> Kinetics-like (many samples; server-side distillation)
+
+    Clips render at the model's input shape: the paper's 8×112×112 for a
+    published-width resnet3d, 4×16×16 for its ``.reduced()`` preset.
     """
     if cfg.family == "resnet3d":
+        from repro.models.resnet3d import input_shape
+        frames, size = input_shape(cfg, 1)[1:3]
         return SyntheticActionDataset(
             num_classes=min(cfg.num_classes, 16 if small else 32),
             samples_per_class=8 if small else 64,
+            frames=frames, size=size,
             noise=0.5 if small else 0.3,
             seed=seed)
     return SyntheticLMDataset(vocab=cfg.vocab_size,

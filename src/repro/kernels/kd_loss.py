@@ -34,6 +34,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+# Mosaic tiles the last two dims of every block by (8, 128): a block dim
+# must be a multiple of its tile or span the whole array dim. Per-row
+# values (labels, the valid mask, the output and the accumulators) are
+# therefore (rows, 1) columns, and rows pad to a multiple of _ROW_BLOCK.
+_ROW_BLOCK = 8
+_VOCAB_BLOCK = 512
+
+
 def _kernel(s_ref, t_ref, lab_ref, v_ref, out_ref,
             m_ref, l_ref, gold_ref, sq_ref,
             *, alpha: float, inv_t: float, vb: int, num_vt: int, vocab: int):
@@ -48,7 +56,7 @@ def _kernel(s_ref, t_ref, lab_ref, v_ref, out_ref,
 
     s = s_ref[...].astype(jnp.float32)              # (rb, vb)
     t = t_ref[...].astype(jnp.float32)
-    lab = lab_ref[...]                              # (rb,)
+    lab = lab_ref[...]                              # (rb, 1)
     rb = s.shape[0]
 
     # mask out padding columns of the last tile
@@ -58,19 +66,19 @@ def _kernel(s_ref, t_ref, lab_ref, v_ref, out_ref,
 
     # online logsumexp
     m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s_m, axis=-1))
+    m_new = jnp.maximum(m_prev, jnp.max(s_m, axis=-1, keepdims=True))
     l_ref[...] = l_ref[...] * jnp.exp(m_prev - m_new) \
-        + jnp.sum(jnp.where(valid, jnp.exp(s_m - m_new[:, None]), 0.0),
-                  axis=-1)
+        + jnp.sum(jnp.where(valid, jnp.exp(s_m - m_new), 0.0),
+                  axis=-1, keepdims=True)
     m_ref[...] = m_new
 
     # gold logit gather (label may fall in this tile)
-    hit = col == lab[:, None]
-    gold_ref[...] += jnp.sum(jnp.where(hit, s, 0.0), axis=-1)
+    hit = col == lab
+    gold_ref[...] += jnp.sum(jnp.where(hit, s, 0.0), axis=-1, keepdims=True)
 
     # running squared error (zero on padding), temperature-scaled
     diff = jnp.where(valid, (s - t) * inv_t, 0.0)
-    sq_ref[...] += jnp.sum(diff * diff, axis=-1)
+    sq_ref[...] += jnp.sum(diff * diff, axis=-1, keepdims=True)
 
     @pl.when(j == num_vt - 1)
     def _done():
@@ -83,20 +91,19 @@ def _kernel(s_ref, t_ref, lab_ref, v_ref, out_ref,
 
 def kd_loss_pallas(student_logits, teacher_logits, labels, alpha: float,
                    temperature: float = 1.0, valid=None,
-                   row_block: int = 8, vocab_block: int = 512,
                    interpret: bool = True):
     """Per-row fused loss. student/teacher: (R, V); labels (R,) int32.
 
-    Returns (R,) float32. Rows are padded to row_block; vocab tiles are
-    masked in-kernel so any (R, V) works. ``valid`` (R,) marks live rows
-    (None = all live); masked rows return exactly 0.0. ``alpha`` and
-    ``temperature`` are trace-time statics.
+    Returns (R,) float32. Rows are padded to a multiple of the row block;
+    vocab tiles are masked in-kernel so any (R, V) works. ``valid`` (R,)
+    marks live rows (None = all live); masked rows return exactly 0.0.
+    ``alpha`` and ``temperature`` are trace-time statics.
     """
     R, V = student_logits.shape
     if valid is None:
         valid = jnp.ones((R,), jnp.float32)
     valid = valid.astype(jnp.float32)
-    rb = min(row_block, R)
+    rb = _ROW_BLOCK
     pad_r = (-R) % rb
     if pad_r:
         student_logits = jnp.pad(student_logits, ((0, pad_r), (0, 0)))
@@ -104,7 +111,7 @@ def kd_loss_pallas(student_logits, teacher_logits, labels, alpha: float,
         labels = jnp.pad(labels, (0, pad_r))
         valid = jnp.pad(valid, (0, pad_r))          # pad rows are invalid
     Rp = R + pad_r
-    vb = min(vocab_block, V)
+    vb = min(_VOCAB_BLOCK, V)
     num_vt = pl.cdiv(V, vb)
     pad_v = num_vt * vb - V
     if pad_v:
@@ -116,6 +123,7 @@ def kd_loss_pallas(student_logits, teacher_logits, labels, alpha: float,
     # so these float() are trace-time constants, not device syncs.
     alpha_c = float(alpha)                # repro-lint: disable=R2
     inv_t = 1.0 / float(temperature)      # repro-lint: disable=R2
+    row = pl.BlockSpec((rb, 1), lambda i, j: (i, 0))
     out = pl.pallas_call(
         functools.partial(_kernel, alpha=alpha_c, inv_t=inv_t, vb=vb,
                           num_vt=num_vt, vocab=V),
@@ -123,20 +131,21 @@ def kd_loss_pallas(student_logits, teacher_logits, labels, alpha: float,
         in_specs=[
             pl.BlockSpec((rb, vb), lambda i, j: (i, j)),
             pl.BlockSpec((rb, vb), lambda i, j: (i, j)),
-            pl.BlockSpec((rb,), lambda i, j: (i,)),
-            pl.BlockSpec((rb,), lambda i, j: (i,)),
+            row,
+            row,
         ],
-        out_specs=pl.BlockSpec((rb,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Rp,), jnp.float32),
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((Rp, 1), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((rb,), jnp.float32),   # running max m
-            pltpu.VMEM((rb,), jnp.float32),   # running sumexp l
-            pltpu.VMEM((rb,), jnp.float32),   # gold logit
-            pltpu.VMEM((rb,), jnp.float32),   # running Σ((s-t)/T)²
+            pltpu.VMEM((rb, 1), jnp.float32),   # running max m
+            pltpu.VMEM((rb, 1), jnp.float32),   # running sumexp l
+            pltpu.VMEM((rb, 1), jnp.float32),   # gold logit
+            pltpu.VMEM((rb, 1), jnp.float32),   # running Σ((s-t)/T)²
         ],
         interpret=interpret,
-    )(student_logits, teacher_logits, labels, valid)
-    return out[:R]
+    )(student_logits, teacher_logits, labels.reshape(Rp, 1),
+      valid.reshape(Rp, 1))
+    return out[:R, 0]
 
 
 # ---------------------------------------------------------------------------

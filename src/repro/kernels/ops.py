@@ -1,14 +1,13 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this CPU container kernels run with interpret=True (Pallas executes the
-kernel body in Python, validating the exact TPU program); on a real TPU
-backend set REPRO_PALLAS_INTERPRET=0 (or rely on the auto-detect) to lower
-to Mosaic.
+The backend decides how a kernel runs: on TPU it lowers to Mosaic, on CPU
+it runs in interpret mode (Pallas executes the kernel body in Python,
+validating the exact TPU program; this is how the tests run). Any other
+backend raises rather than silently interpreting.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -23,10 +22,13 @@ from repro.kernels.swa_attention import (extent_decode_attend_pallas,
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+    """Interpret mode exactly on the CPU backend; Mosaic on TPU."""
+    backend = jax.default_backend()
+    if backend in ("cpu", "tpu"):
+        return backend == "cpu"
+    raise RuntimeError(
+        f"Pallas kernels lower only for TPU (or interpret on CPU); the "
+        f"default backend is {backend!r}")
 
 
 # Module-level kernel leaf wrappers: one jit per op for the whole process,

@@ -31,6 +31,7 @@ import jax
 
 from repro.configs import get_config
 from repro.core import distill, simulator
+from repro.core.compile_cache import use_persistent_cache
 from repro.core.fleet import Fleet
 from repro.data import BatchLoader, iid_partition, make_dataset_for
 from repro.launch.train import build_fleet
@@ -51,8 +52,8 @@ def params_digest(params) -> str:
     return h.hexdigest()
 
 
-def _finetune(params, cfg: ModelConfig, fed: FedConfig, ds, batch: int,
-              mode: str, engine: str, seed: int):
+def finetune(params, cfg: ModelConfig, fed: FedConfig, ds, batch: int,
+             mode: str, engine: str, seed: int):
     """Stage 2: federated fine-tune from ``params`` over an iid partition
     of the clients' reduced local dataset."""
     parts = iid_partition(max(len(ds), fed.num_clients * 8),
@@ -119,7 +120,7 @@ def run_pipeline(arch: str = "resnet3d-18", teacher: str = "resnet3d-34",
     # class programs as the server's full set, so KD transfer is real.
     fed = FedConfig(num_clients=clients, global_epochs=epochs, seed=seed)
     ds = make_dataset_for(cfg, small=True, seed=seed)
-    res = _finetune(params, cfg, fed, ds, batch, mode, engine, seed)
+    res = finetune(params, cfg, fed, ds, batch, mode, engine, seed)
     params = res.params
     held_out = list(ds.batches(batch, eval_steps, seed=777)) \
         if hasattr(ds, "batches") else []
@@ -133,7 +134,7 @@ def run_pipeline(arch: str = "resnet3d-18", teacher: str = "resnet3d-34",
         # same fine-tune from a random init: the KD baseline of Table II
         scratch0 = registry.init_params(
             jax.random.fold_in(jax.random.PRNGKey(seed), 1), cfg)
-        sres = _finetune(scratch0, cfg, fed, ds, batch, mode, engine, seed)
+        sres = finetune(scratch0, cfg, fed, ds, batch, mode, engine, seed)
         sacc = distill.evaluate(sres.params, cfg, held_out) \
             if held_out else 0.0
         report["scratch"] = {"final_loss": sres.final_loss,
@@ -186,4 +187,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_persistent_cache()
     raise SystemExit(main())

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from repro.checkpoint import save_params
 from repro.configs import get_config
 from repro.core import distill, simulator
+from repro.core.compile_cache import use_persistent_cache
 from repro.core.algorithms import ALGORITHMS, make_algorithm
 from repro.core.fleet import (ASYNC_ENGINES, EngineSpec, Fleet, FleetSpec,
                               JETSON_FLEET_HMDB51)
@@ -207,4 +208,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_persistent_cache()
     raise SystemExit(main())

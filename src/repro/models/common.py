@@ -114,11 +114,9 @@ def chunked_lm_loss(hidden: jax.Array, lm_head: jax.Array, labels: jax.Array,
         mask = (l != -100).astype(jnp.float32)
         return jnp.sum((lse - gold) * mask), jnp.sum(mask)
 
-    def body(carry, xs):
-        h, l = xs
-        nll, cnt = one(h, l)
-        return (carry[0] + nll, carry[1] + cnt), None
-
-    (nll, cnt), _ = jax.lax.scan(body, (jnp.float32(0), jnp.float32(0)),
+    # per-chunk sums come out as scan outputs, not a carry: a constant
+    # initial carry would be replicated while the sums vary over a
+    # shard_map's client axis, and scan requires the two to match
+    _, (nll, cnt) = jax.lax.scan(lambda c, xs: (c, one(*xs)), None,
                                  (hid, lab))
-    return nll / jnp.maximum(cnt, 1.0)
+    return jnp.sum(nll) / jnp.maximum(jnp.sum(cnt), 1.0)

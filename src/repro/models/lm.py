@@ -182,22 +182,23 @@ def forward_hidden(params, cfg: ModelConfig, tokens: jax.Array,
     positions = jnp.arange(S)
     win = windows(cfg)
 
-    def body(carry, xs):
-        x, aux = carry
+    # per-layer aux losses come out as scan outputs, not a carry: under a
+    # shard_map a constant initial carry would not match the data-varying
+    # sums (see models.common.chunked_lm_loss)
+    def body(x, xs):
         lp, w = xs
         x, a, _ = _layer(cfg, lp, x, w, positions, "train", q_chunk=q_chunk,
                          moe_ctx=moe_ctx)
         if act_pspec is not None:
             x = jax.lax.with_sharding_constraint(x, act_pspec)
-        return (x, aux + a), None
+        return x, a
 
     if remat:
         body = jax.checkpoint(body,
                               policy=jax.checkpoint_policies.nothing_saveable)
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0.0)),
-                               (params["layers"], win))
+    x, aux = jax.lax.scan(body, x, (params["layers"], win))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return x, aux
+    return x, jnp.sum(aux)
 
 
 def loss_fn(params, cfg: ModelConfig, batch: dict, remat: bool = True,

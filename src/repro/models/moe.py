@@ -4,8 +4,7 @@ Two dispatch paths:
 
 - **Local (single host / tests)**: scatter/gather into an (E, C, d) buffer.
 - **Distributed (`moe_ctx` given)**: the dispatch and combine run inside
-  ``shard_map`` (the version-portable wrapper in ``sharding.specs``) over
-  the data axes — each data shard routes its local
+  ``jax.shard_map`` over the data axes — each data shard routes its local
   tokens into a *local* capacity slice (E, C_loc, d), the shards concatenate
   into the global (E, C, d) buffer along the capacity dim, and the expert
   matmuls run under pjit with expert weights sharded over 'model'
@@ -26,7 +25,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.common import activation, fan_in_init
-from repro.sharding.specs import shard_map
 from repro.types import MoEConfig
 
 
@@ -146,12 +144,12 @@ def moe_forward(p, x, moe: MoEConfig, act: str = "silu", moe_ctx=None,
             return eb, weights, slot, keep, _pmean(frac, dp), \
                 _pmean(mean_p, dp)
 
-        eb, weights, slot, keep, frac, mean_p = shard_map(
+        eb, weights, slot, keep, frac, mean_p = jax.shard_map(
             disp, mesh=mesh,
             in_specs=(P(None, None), P(dp, None)),
             out_specs=(P(None, dp, None), P(dp, None), P(dp), P(dp),
                        P(), P()),
-            check_replication=False,
+            check_vma=False,
         )(p["router"], xt)
 
         out_e = _expert_ffn(p, eb, act)
@@ -160,11 +158,11 @@ def moe_forward(p, x, moe: MoEConfig, act: str = "silu", moe_ctx=None,
             T_loc = weights.shape[0]
             return _combine(out_loc, slot, keep, weights, T_loc, k)
 
-        out = shard_map(
+        out = jax.shard_map(
             comb, mesh=mesh,
             in_specs=(P(None, dp, None), P(dp, None), P(dp), P(dp)),
             out_specs=P(dp, None),
-            check_replication=False,
+            check_vma=False,
         )(out_e, weights, slot, keep)
 
     out = out.reshape(B, S, d)

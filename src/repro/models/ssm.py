@@ -93,7 +93,9 @@ def ssd_chunked(xh, dt, A, Bm, Cm, chunk: int, h0=None):
     chunk_decay = jnp.exp(jnp.sum(dA, axis=2))          # (b,c,h)
 
     # --- inter-chunk recurrence ---
-    init = h0 if h0 is not None else jnp.zeros((Bsz, H, P, N), x.dtype)
+    # zeros_like keeps the chunk states' shard_map typing, so the scan
+    # carry matches its data-varying output
+    init = h0 if h0 is not None else jnp.zeros_like(states[:, 0])
 
     def body(h, xs):
         st, dec = xs                                    # (b,h,p,n), (b,h)

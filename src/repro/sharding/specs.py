@@ -19,25 +19,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.types import ModelConfig, ShapeConfig
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs,
-              check_replication: bool = True):
-    """Version-portable ``shard_map``.
-
-    Newer jax exposes ``jax.shard_map`` (replication checking spelled
-    ``check_vma``); 0.4.x only ships ``jax.experimental.shard_map``
-    (spelled ``check_rep``). Both the MoE distributed dispatch
-    (models/moe.py) and the sharded federated sync round
-    (core/fed_engine.py) go through this wrapper so they run on either.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             check_vma=check_replication)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_replication)
-
-
 def fed_round_specs(mesh: Mesh) -> dict:
     """PartitionSpecs for the shard_map'ed federated sync round.
 

@@ -1,4 +1,8 @@
-"""Shared compile cache: bucketing math + jit-pool compile accounting."""
+"""Shared compile cache: bucketing math + jit-pool compile accounting,
+and where the persistent compilation cache is placed."""
+import pathlib
+
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -11,6 +15,26 @@ def test_next_pow2():
         == [1, 2, 4, 4, 8, 32, 64]
     with pytest.raises(ValueError):
         cc.next_pow2(0)
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "checkout"])
+def test_persistent_cache_placement(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR wins and is left to JAX; otherwise the
+    cache sits at the fixed <checkout>/.jax_cache."""
+    before = jax.config.jax_compilation_cache_dir
+    checkout = pathlib.Path(__file__).resolve().parents[1]
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert cc.use_persistent_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            path = cc.use_persistent_cache()
+            assert path == str(checkout / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_bucket_for_clamps_and_caps():

@@ -236,6 +236,30 @@ def test_sharded_round_single_device_smoke(setup):
         is engine
 
 
+@pytest.mark.parametrize("arch", ["mamba2-130m", "grok-1-314b",
+                                  "hymba-1.5b"])
+def test_sharded_round_model_families(arch):
+    """The shard_map round type-checks every LM family's loss: the SSD
+    state, MoE aux and chunked-CE accumulators vary over the client axis
+    like the batches they come from, and the round equals the vmap one."""
+    from repro.configs import get_config
+    from repro.launch.mesh import make_fleet_mesh
+    cfg = get_config(arch).reduced()
+    params = registry.init_params(jax.random.PRNGKey(0), cfg)
+    fed = FedConfig(num_clients=2, local_iters_min=1, local_iters_max=2,
+                    lr=0.01)
+    ds = SyntheticLMDataset(vocab=cfg.vocab_size, seq_len=8, seed=0)
+    stacks = [stack_batches(ds.batches(2, 2, seed=k)) for k in range(2)]
+    iters = np.array([2, 1], np.int32)
+    g_sh, l_sh = fed_engine.make_sharded_sync_round(
+        cfg, fed, mesh=make_fleet_mesh())(params, stacks, iters=iters)
+    g_pad, l_pad = fed_engine.make_sync_round(cfg, fed)(params, stacks,
+                                                        iters=iters)
+    np.testing.assert_allclose(np.asarray(l_sh), np.asarray(l_pad),
+                               rtol=1e-5, atol=1e-5)
+    tree_allclose(g_sh, g_pad)
+
+
 def test_run_sync_shard_engine_parity(setup):
     params, fed, ds = setup
     ra = simulator.run_sync(params, TINY, fed, JETSON_FLEET_HMDB51,

@@ -31,6 +31,20 @@ def test_kd_loss_sweep(R, V, dtype, alpha, rng):
                                    jnp.max(jnp.abs(want)))))
 
 
+@pytest.mark.parametrize("backend,interpret", [("cpu", True),
+                                               ("tpu", False),
+                                               ("gpu", None)])
+def test_interpret_follows_backend(monkeypatch, backend, interpret):
+    """Interpret mode on CPU only, Mosaic on TPU, and no silent
+    interpreter on any other backend."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is interpret
+
+
 def test_kd_loss_jit_wrapper_means(rng):
     s = jnp.asarray(rng.standard_normal((4, 7, 128)), jnp.float32)
     t = jnp.asarray(rng.standard_normal((4, 7, 128)), jnp.float32)

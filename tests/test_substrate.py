@@ -121,6 +121,22 @@ def test_action_dataset_classes_distinguishable():
     assert diff > same * 0.9
 
 
+@pytest.mark.parametrize("reduced,clip", [(False, (8, 112, 112, 3)),
+                                           (True, (4, 16, 16, 3))],
+                         ids=["published", "reduced"])
+def test_action_dataset_renders_at_model_input_shape(reduced, clip):
+    """Published widths train on the paper's 8x112x112 clips; the reduced
+    preset keeps the small clips that CPU tests can afford."""
+    from repro.configs import get_config
+    from repro.data import make_dataset_for
+    cfg = get_config("resnet3d-18")
+    if reduced:
+        cfg = cfg.reduced()
+    b = next(make_dataset_for(cfg).batches(2, 1))
+    assert b["clips"].shape == (2,) + clip
+    assert b["clips"].dtype == np.float32
+
+
 def test_lm_dataset_shapes():
     ds = SyntheticLMDataset(vocab=64, seq_len=16, seed=0)
     b = next(ds.batches(3, 1))
