@@ -1,0 +1,14 @@
+"""call_share.<kind>: the share of the traced window the host spent in
+calls into the engines' compiled programs (argument transfer and
+enqueue): 100·|union of the program's ``engine.*`` spans| / window
+(``repro.obs``). None without the program's record."""
+from trace_reduce import _union
+
+
+def read(name, m):
+    rec = m.get("program")
+    if not rec or not m["window_s"]:
+        return None
+    busy = _union([s, s + d] for s, d, n, _, _ in rec["spans"]
+                  if n.startswith("engine."))
+    return 100.0 * sum(e - s for s, e in busy) * 1e-9 / m["window_s"]

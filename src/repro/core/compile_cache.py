@@ -36,6 +36,8 @@ import warnings
 
 import jax
 
+from repro import obs
+
 # <checkout>/src/repro/core/compile_cache.py -> <checkout>
 _CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
 
@@ -81,6 +83,7 @@ class JitCache:
     def __init__(self):
         self._jits: dict = {}
         self._seen: dict = {}     # key -> set of arg shape/dtype signatures
+        self._spans: dict = {}    # key -> name of the span around a call
 
     @staticmethod
     def _signature(args) -> tuple:
@@ -90,17 +93,23 @@ class JitCache:
             for leaf in jax.tree_util.tree_leaves(args))
 
     def call(self, name, fn, donate: tuple, args):
+        """Run entry ``name`` on ``args``, inside the span
+        ``engine.<name>`` (``<name>[0]`` for a tuple name): the host side
+        of argument transfer and enqueue."""
         key = (name, donate)
         if key not in self._jits:
             self._jits[key] = jax.jit(fn, donate_argnums=donate)
             self._seen[key] = set()
-        self._seen[key].add(self._signature(args))
-        if not donate:
-            return self._jits[key](*args)
-        with warnings.catch_warnings():
-            warnings.filterwarnings(
-                "ignore", message="Some donated buffers were not usable")
-            return self._jits[key](*args)
+            self._spans[key] = "engine." + str(
+                name[0] if isinstance(name, tuple) else name)
+        with obs.span(self._spans[key]):
+            self._seen[key].add(self._signature(args))
+            if not donate:
+                return self._jits[key](*args)
+            with warnings.catch_warnings():
+                warnings.filterwarnings(
+                    "ignore", message="Some donated buffers were not usable")
+                return self._jits[key](*args)
 
     def _entry_size(self, key) -> int:
         """Traced programs for one (entry point, donate) pool entry, with

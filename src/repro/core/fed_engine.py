@@ -64,6 +64,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import algorithms
 from repro.core.compile_cache import JitCache as _JitCache
 from repro.models import registry
@@ -91,8 +92,11 @@ def stack_client_batches(client_batch_stacks: Sequence[Any]):
             f"heterogeneous client batch stacks {shapes}; use "
             "pad_client_batches to pad per-client H to a common H_max and "
             "run the padded masked-scan round (one batched program)")
-    return jax.tree_util.tree_map(
-        lambda *leaves: np.stack(leaves), *client_batch_stacks)
+    with obs.span("fed.pad"):
+        stacked = jax.tree_util.tree_map(
+            lambda *leaves: np.stack(leaves), *client_batch_stacks)
+        _count_staged(stacked)
+    return stacked
 
 
 def pad_client_batches(client_batch_stacks: Sequence[Any],
@@ -126,30 +130,57 @@ def pad_client_batches(client_batch_stacks: Sequence[Any],
     ref_flat, treedef = jax.tree_util.tree_flatten(ref)
     trailing = [(tuple(l.shape[1:]), np.asarray(l).dtype) for l in ref_flat]
 
-    padded = []
-    for s, h in zip(client_batch_stacks, lens):
-        if h == 0:
-            flat = [np.zeros((H_max,) + shp, dt) for shp, dt in trailing]
+    with obs.span("fed.pad"):
+        padded = []
+        for s, h in zip(client_batch_stacks, lens):
+            if h == 0:
+                flat = [np.zeros((H_max,) + shp, dt) for shp, dt in trailing]
+                padded.append(jax.tree_util.tree_unflatten(treedef, flat))
+                continue
+            if jax.tree_util.tree_structure(s) != treedef:
+                raise ValueError(
+                    "client batch stacks disagree on pytree structure "
+                    "(keys); matching leaf shapes cannot substitute for "
+                    "matching keys")
+            flat = [np.asarray(l) for l in jax.tree_util.tree_leaves(s)]
+            if [(tuple(l.shape[1:]), l.dtype) for l in flat] != trailing:
+                raise ValueError(
+                    "client batch stacks disagree on per-batch "
+                    "shapes/dtypes; padding only evens out iteration "
+                    "counts — use the per-client fallback for truly ragged "
+                    "batches")
+            pad = H_max - h
+            if pad:
+                flat = [np.concatenate(
+                    [l, np.zeros((pad,) + l.shape[1:], l.dtype)])
+                    for l in flat]
             padded.append(jax.tree_util.tree_unflatten(treedef, flat))
-            continue
-        if jax.tree_util.tree_structure(s) != treedef:
-            raise ValueError(
-                "client batch stacks disagree on pytree structure (keys); "
-                "matching leaf shapes cannot substitute for matching keys")
-        flat = [np.asarray(l) for l in jax.tree_util.tree_leaves(s)]
-        if [(tuple(l.shape[1:]), l.dtype) for l in flat] != trailing:
-            raise ValueError(
-                "client batch stacks disagree on per-batch shapes/dtypes; "
-                "padding only evens out iteration counts — use the "
-                "per-client fallback for truly ragged batches")
-        pad = H_max - h
-        if pad:
-            flat = [np.concatenate(
-                [l, np.zeros((pad,) + l.shape[1:], l.dtype)]) for l in flat]
-        padded.append(jax.tree_util.tree_unflatten(treedef, flat))
-    stacked = jax.tree_util.tree_map(
-        lambda *leaves: np.stack(leaves), *padded)
+        stacked = jax.tree_util.tree_map(
+            lambda *leaves: np.stack(leaves), *padded)
+        _count_staged(stacked)
     return stacked, np.asarray(lens, np.int32)
+
+
+def _count_staged(tree) -> None:
+    """``staged_bytes``: the host bytes of a stack built for transfer."""
+    if obs.enabled():
+        obs.count("staged_bytes", sum(
+            l.nbytes for l in jax.tree_util.tree_leaves(tree)))
+
+
+def _count_clip_steps(stacked, iters=None, clients: bool = True) -> None:
+    """``clip_steps_executed`` and ``clip_steps_useful`` of one call on a
+    client-stacked pytree (leaves (n, H_max, batch, ...); one client's
+    (H, batch, ...) with ``clients=False``): n·H_max·batch run, ΣH^k·batch
+    of them unmasked (all when ``iters`` is None). An ``iters`` on the
+    device is not read back: that call goes uncounted."""
+    if not obs.enabled() or isinstance(iters, jax.Array):
+        return
+    shape = jax.tree_util.tree_leaves(stacked)[0].shape
+    n, H, batch = shape[:3] if clients else (1,) + tuple(shape[:2])
+    obs.count("clip_steps_executed", n * H * batch)
+    obs.count("clip_steps_useful",
+              (n * H if iters is None else int(np.sum(iters))) * batch)
 
 
 def _batch_len(stacked) -> int:
@@ -319,6 +350,7 @@ class ClientRun:
             mask = trainable_mask(params_global, self.fed.trainable)
         server_ctx, state = self._alg_inputs(params_global, server_ctx,
                                              state)
+        _count_clip_steps(stacked, clients=False)
         return self._jits.call("run", self._run, (1,) if donate else (),
                                (params_global, stacked, mask, server_ctx,
                                 state))
@@ -353,6 +385,7 @@ class ClientRun:
             params_global, server_ctx, states,
             ids=(client_ids if client_ids is not None
                  else range(_batch_len(client_stacks))))
+        _count_clip_steps(client_stacks, iters)
         return self._jits.call(
             "batch", self._run_padded_batch, (1,) if donate else (),
             (params_global, client_stacks, jnp.asarray(iters, jnp.int32),
@@ -558,6 +591,7 @@ class SyncRound:
             params_global, server_ctx, states,
             ids=(client_ids if client_ids is not None else range(n)))
         argnums = self._donated(donate, donate_params)
+        _count_clip_steps(client_stacks, iters)
         if iters is None:
             return self._jits.call(
                 "rnd", self._rnd, argnums,
@@ -698,6 +732,7 @@ class ShardedSyncRound(SyncRound):
             iters = np.concatenate([iters, np.zeros((pad,), np.int32)])
             states = jax.tree_util.tree_map(
                 lambda l: jnp.concatenate([l] + [l[:1]] * pad), states)
+        _count_clip_steps(client_stacks, iters)
         out = self._jits.call(
             "shard", self._sharded_rnd,
             self._donated(donate, donate_params),

@@ -27,6 +27,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import algorithms, compile_cache, fed_engine
 from repro.core.fedasync import cached_client_step, make_client_step
 from repro.data.synthetic import stack_batches
@@ -139,9 +140,12 @@ def fedavg_round(params_global, client_batches: Sequence, cfg: ModelConfig,
             # stack straight to (n_clients, H, ...) — one host copy, not
             # a per-client stack followed by a cross-client restack
             keys = list(client_lists[0][0])
-            stacked_clients = {
-                k: np.stack([[b[k] for b in bl] for bl in client_lists])
-                for k in keys}
+            with obs.span("fed.pad"):
+                stacked_clients = {
+                    k: np.stack([[b[k] for b in bl] for bl in client_lists])
+                    for k in keys}
+                obs.count("staged_bytes", sum(
+                    v.nbytes for v in stacked_clients.values()))
             if engine is None:
                 engine = fed_engine.make_sync_round(cfg, fed,
                                                     algorithm=algorithm)
@@ -152,8 +156,9 @@ def fedavg_round(params_global, client_batches: Sequence, cfg: ModelConfig,
                          weights=weights, mask=mask, donate=True,
                          donate_params=donate_params, **alg_kw)
             new_global, losses = _alg_round_commit(algorithm, ids, out)
-            return new_global, [[float(x) for x in row]
-                                for row in np.asarray(losses)]
+            with obs.span("fed.readback"):
+                losses = np.asarray(losses)
+            return new_global, [[float(x) for x in row] for row in losses]
         return _padded_round(params_global, client_lists, cfg, fed,
                              engine, mask, data_sizes, donate_params,
                              algorithm, client_ids)
@@ -184,12 +189,14 @@ def _padded_round(params_global, client_lists, cfg, fed, engine, mask,
     H_max = max(fed.local_iters_max, max(len(bl) for bl in client_lists))
     iters = np.asarray([len(bl) for bl in client_lists], np.int32)
     stacked = {}
-    for k, v in ref.items():
-        out = np.zeros((n, H_max) + np.shape(v), np.asarray(v).dtype)
-        for c, bl in enumerate(client_lists):
-            for i, b in enumerate(bl):
-                out[c, i] = b[k]
-        stacked[k] = out
+    with obs.span("fed.pad"):
+        for k, v in ref.items():
+            out = np.zeros((n, H_max) + np.shape(v), np.asarray(v).dtype)
+            for c, bl in enumerate(client_lists):
+                for i, b in enumerate(bl):
+                    out[c, i] = b[k]
+            stacked[k] = out
+            obs.count("staged_bytes", out.nbytes)
     if engine is None:
         engine = fed_engine.make_sync_round(cfg, fed, algorithm=algorithm)
     weights = _client_weights(n, data_sizes)
@@ -198,7 +205,8 @@ def _padded_round(params_global, client_lists, cfg, fed, engine, mask,
                  mask=mask, iters=iters, donate=True,
                  donate_params=donate_params, **alg_kw)
     new_global, losses = _alg_round_commit(algorithm, ids, out)
-    losses = np.asarray(losses)
+    with obs.span("fed.readback"):
+        losses = np.asarray(losses)
     return new_global, [[float(x) for x in row[:h]]
                         for row, h in zip(losses, iters)]
 

@@ -29,6 +29,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import algorithms, fed_engine, fedasync, fedavg
 from repro.core.compression import roundtrip
 from repro.core.fedasync import ServerState
@@ -309,7 +310,8 @@ def run_async(params0, cfg: ModelConfig, fed: FedConfig,
                         server_ctx=alg.ctx_for(server.params),
                         states=alg.stacked_states(server.params, live),
                         client_ids=live)
-                    la = jax.device_get(loss_arr)    # single host sync
+                    with obs.span("fed.readback"):    # single host sync
+                        la = jax.device_get(loss_arr)
                     per_client = run.unstack((w_news, new_states, msgs),
                                              len(live))
                     for j, k in enumerate(live):
@@ -321,7 +323,8 @@ def run_async(params0, cfg: ModelConfig, fed: FedConfig,
                     w_news, loss_arr = run.run_batch(
                         server.params, padded, iters, mask=mask,
                         donate=True)
-                    la = jax.device_get(loss_arr)    # single host sync
+                    with obs.span("fed.readback"):    # single host sync
+                        la = jax.device_get(loss_arr)
                     per_client = run.unstack(
                         w_news, len(live))       # one dispatch, not n×leaves
                     for j, k in enumerate(live):
@@ -338,14 +341,16 @@ def run_async(params0, cfg: ModelConfig, fed: FedConfig,
                         server_ctx=alg.ctx_for(server.params),
                         state=alg.state_for(k, server.params))
                     alg.store_state(k, st)
-                    results[k] = ((w, msg),
-                                  [float(jax.device_get(loss_arr)[-1])])
+                    with obs.span("fed.readback"):
+                        la = jax.device_get(loss_arr)
+                    results[k] = ((w, msg), [float(la[-1])])
                 else:
                     w_new, loss_arr = run(server.params, stacks[k],
                                           mask=mask, donate=True)
                     # one explicit transfer; indexing happens on host
-                    results[k] = (w_new,
-                                  [float(jax.device_get(loss_arr)[-1])])
+                    with obs.span("fed.readback"):
+                        la = jax.device_get(loss_arr)
+                    results[k] = (w_new, [float(la[-1])])
         elif alg is not None:
             for k in ks:
                 w_new, st, msg, losses = algorithms.client_update_loop(
@@ -362,32 +367,33 @@ def run_async(params0, cfg: ModelConfig, fed: FedConfig,
         return results
 
     def dispatch(ks, now: float):
-        tau = server.t
-        for k in ks:
-            if k not in H:
-                H[k] = fleet.iters(k, fed)
-            inflight.add(k)
-        # run the local training NOW (numerically); finish time is virtual
-        results = _run_clients(ks)
-        for k in ks:
-            w_new, losses = results[k]
-            if alg is not None and (fed.compress_bits or alg.wire_always):
-                # the algorithm's wire codec (int8/int4 deltas, low-rank
-                # factors); decode against the anchor the server handed out
-                w, msg = w_new if stateful else (w_new, ())
-                wire = alg.encode(w, msg, server.params, fed)
-                w, msg = alg.decode(wire, server.params, fed)
-                w_new = (w, msg) if stateful else w
-            elif fed.compress_bits:
-                # int8 delta on the wire; server reconstructs against the
-                # anchor it handed out (communication-efficient FL, §II)
-                w_new, _ = roundtrip(w_new, server.params,
-                                     fed.compress_bits)
-            dt = _client_time(fleet.profile(k), H[k], iters_per_epoch, rng,
-                              jitter)
-            sched.push(now + dt, k, w_new, tau,
-                       losses[-1] if losses else math.nan)
-            trace.append(TraceEvent(now, "dispatch", k, tau))
+        with obs.span("sim.dispatch"):
+            tau = server.t
+            for k in ks:
+                if k not in H:
+                    H[k] = fleet.iters(k, fed)
+                inflight.add(k)
+            # run the local training NOW (numerically); finish time is virtual
+            results = _run_clients(ks)
+            for k in ks:
+                w_new, losses = results[k]
+                if alg is not None and (fed.compress_bits or alg.wire_always):
+                    # the algorithm's wire codec (int8/int4 deltas, low-rank
+                    # factors); decode against the anchor the server handed out
+                    w, msg = w_new if stateful else (w_new, ())
+                    wire = alg.encode(w, msg, server.params, fed)
+                    w, msg = alg.decode(wire, server.params, fed)
+                    w_new = (w, msg) if stateful else w
+                elif fed.compress_bits:
+                    # int8 delta on the wire; server reconstructs against the
+                    # anchor it handed out (communication-efficient FL, §II)
+                    w_new, _ = roundtrip(w_new, server.params,
+                                         fed.compress_bits)
+                dt = _client_time(fleet.profile(k), H[k], iters_per_epoch, rng,
+                                  jitter)
+                sched.push(now + dt, k, w_new, tau,
+                           losses[-1] if losses else math.nan)
+                trace.append(TraceEvent(now, "dispatch", k, tau))
 
     if m_inflight < fleet.population:
         kickoff = [int(k) for k in fleet.sample(sample_rng, m_inflight)]
@@ -397,27 +403,33 @@ def run_async(params0, cfg: ModelConfig, fed: FedConfig,
 
     now = 0.0
     while server.t < fed.global_epochs and len(sched):
-        group = sched.pop_window(server.t, fed.max_staleness,
-                                 fed.global_epochs - server.t)
-        t0 = server.t
-        if stateful:
-            server, new_ctx, stals, betas = fedasync.server_receive_many(
-                server, [(w, msg, tau)
-                         for _, _, (w, msg), tau, _ in group], fed,
-                algorithm=alg, server_ctx=alg.ctx_for(server.params))
-            alg.set_ctx(new_ctx)
-        else:
-            server, stals, betas = fedasync.server_receive_many(
-                server, [(w_new, tau) for _, _, w_new, tau, _ in group],
-                fed, mix_many=mix_many)
-        for i, ((ft, k, _, _, loss), st, bt) in enumerate(
-                zip(group, stals, betas)):
-            now = ft
-            staleness_hist[st] = staleness_hist.get(st, 0) + 1
-            trace.append(TraceEvent(ft, "receive", k, t0 + i + 1, st, bt,
-                                    loss))
-            history.append((ft, t0 + i + 1, loss))
-        group_hist[len(group)] = group_hist.get(len(group), 0) + 1
+        with obs.span("sim.receive"):
+            group = sched.pop_window(server.t, fed.max_staleness,
+                                     fed.global_epochs - server.t)
+            t0 = server.t
+            with obs.span("server.mix"):
+                if stateful:
+                    server, new_ctx, stals, betas = \
+                        fedasync.server_receive_many(
+                            server, [(w, msg, tau)
+                                     for _, _, (w, msg), tau, _ in group],
+                            fed, algorithm=alg,
+                            server_ctx=alg.ctx_for(server.params))
+                    alg.set_ctx(new_ctx)
+                else:
+                    server, stals, betas = fedasync.server_receive_many(
+                        server,
+                        [(w_new, tau) for _, _, w_new, tau, _ in group],
+                        fed, mix_many=mix_many)
+            for i, ((ft, k, _, _, loss), st, bt) in enumerate(
+                    zip(group, stals, betas)):
+                now = ft
+                staleness_hist[st] = staleness_hist.get(st, 0) + 1
+                trace.append(TraceEvent(ft, "receive", k, t0 + i + 1, st,
+                                        bt, loss))
+                history.append((ft, t0 + i + 1, loss))
+            group_hist[len(group)] = group_hist.get(len(group), 0) + 1
+            obs.count(obs.UPDATES, len(group))
         if eval_fn is not None and any(
                 t % eval_every == 0 for t in range(t0 + 1, server.t + 1)):
             # the fused mix has no intermediate params: evaluate once at
@@ -519,34 +531,36 @@ def run_sync(params0, cfg: ModelConfig, fed: FedConfig,
     rounds = fed.global_epochs // max(m, 1)
     rounds = max(rounds, 1)
     for r in range(rounds):
-        if m < fleet.population:
-            ids = [int(k) for k in fleet.sample(sample_rng, m)]
-        else:
-            ids = list(range(fleet.population))
-        batches = [fleet.data(k)() for k in ids]
-        if round_engine is not None:
-            # the incoming global (our private copy, or the previous
-            # round's output) is dead after this call: donate it so the
-            # new global reuses its buffers
-            params, losses = fedavg.fedavg_round(params, batches, cfg, fed,
-                                                 engine=round_engine,
-                                                 mask=mask,
-                                                 donate_params=True,
-                                                 algorithm=alg,
-                                                 client_ids=ids)
-        else:
-            params, losses = fedavg.fedavg_round_loop(
-                params, batches, cfg, fed, step=step, opt=opt, mask=mask,
-                algorithm=alg, client_ids=ids)
-        dt = max(_client_time(fleet.profile(k), fed.local_iters_max,
-                              iters_per_epoch, rng, jitter)
-                 for k in ids)
-        if m < fleet.population:
-            fleet.release(ids)
-        now += dt
-        loss = float(np.mean([l[-1] for l in losses if l]))
-        history.append((now, r + 1, loss))
-        trace.append(TraceEvent(now, "round", -1, r + 1, 0, 0.0, loss))
+        with obs.span("sim.round"):
+            if m < fleet.population:
+                ids = [int(k) for k in fleet.sample(sample_rng, m)]
+            else:
+                ids = list(range(fleet.population))
+            batches = [fleet.data(k)() for k in ids]
+            if round_engine is not None:
+                # the incoming global (our private copy, or the previous
+                # round's output) is dead after this call: donate it so the
+                # new global reuses its buffers
+                params, losses = fedavg.fedavg_round(params, batches, cfg, fed,
+                                                     engine=round_engine,
+                                                     mask=mask,
+                                                     donate_params=True,
+                                                     algorithm=alg,
+                                                     client_ids=ids)
+            else:
+                params, losses = fedavg.fedavg_round_loop(
+                    params, batches, cfg, fed, step=step, opt=opt, mask=mask,
+                    algorithm=alg, client_ids=ids)
+            dt = max(_client_time(fleet.profile(k), fed.local_iters_max,
+                                  iters_per_epoch, rng, jitter)
+                     for k in ids)
+            if m < fleet.population:
+                fleet.release(ids)
+            now += dt
+            loss = float(np.mean([l[-1] for l in losses if l]))
+            history.append((now, r + 1, loss))
+            trace.append(TraceEvent(now, "round", -1, r + 1, 0, 0.0, loss))
+            obs.count(obs.UPDATES)
         if eval_fn is not None and (r + 1) % eval_every == 0:
             eval_fn(r + 1, now, params)
     return SimResult(wall_clock_s=now, history=history, trace=trace,

@@ -24,6 +24,8 @@ from typing import Tuple
 
 import numpy as np
 
+from repro import obs
+
 
 @dataclass
 class SyntheticActionDataset:
@@ -124,13 +126,16 @@ def stack_batches(batches, limit: int | None = None):
     client returns the global model unchanged).
     """
     import itertools
-    # islice, not enumerate+break: the latter would pull (and waste) one
-    # batch past the limit, breaking consumption parity with the legacy
-    # ``zip(range(H), batches)`` loop on shared iterators
-    out = list(itertools.islice(batches, limit))
-    if not out:
-        return None
-    return {k: np.stack([b[k] for b in out]) for k in out[0]}
+    with obs.span("data.stack"):
+        # islice, not enumerate+break: the latter would pull (and waste)
+        # one batch past the limit, breaking consumption parity with the
+        # legacy ``zip(range(H), batches)`` loop on shared iterators
+        out = list(itertools.islice(batches, limit))
+        if not out:
+            return None
+        stacked = {k: np.stack([b[k] for b in out]) for k in out[0]}
+        obs.count("staged_bytes", sum(v.nbytes for v in stacked.values()))
+        return stacked
 
 
 def make_dataset_for(cfg, *, small: bool = True, seed: int = 0):
