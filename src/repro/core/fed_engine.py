@@ -57,16 +57,19 @@ The legacy loop remains in place as a parity oracle
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Sequence
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
 
 from repro import obs
 from repro.core import algorithms
 from repro.core.compile_cache import JitCache as _JitCache
+from repro.data.synthetic import count_stacked, device_zeros, stack_trees
 from repro.models import registry
 from repro.optim import sgd, trainable_mask
 from repro.types import FedConfig, ModelConfig
@@ -79,7 +82,10 @@ def stack_client_batches(client_batch_stacks: Sequence[Any]):
     All clients must share the same H and batch shapes (homogeneous sync
     round); raises ValueError otherwise — heterogeneous fleets batch
     through ``pad_client_batches``, which pads per-client H to a common
-    H_max and returns the iteration mask for the padded scan.
+    H_max and returns the iteration mask for the padded scan. Host leaves
+    go to the device as they are and the stack is assembled there
+    (``data.synthetic.stack_trees``): the leaves returned are device
+    arrays.
     """
     if not client_batch_stacks:
         raise ValueError("no client batch stacks")
@@ -93,10 +99,20 @@ def stack_client_batches(client_batch_stacks: Sequence[Any]):
             "pad_client_batches to pad per-client H to a common H_max and "
             "run the padded masked-scan round (one batched program)")
     with obs.span("fed.pad"):
-        stacked = jax.tree_util.tree_map(
-            lambda *leaves: np.stack(leaves), *client_batch_stacks)
-        _count_staged(stacked)
+        stacked = stack_trees(jax.device_put(list(client_batch_stacks)))
+        count_stacked(stacked)
     return stacked
+
+
+# module-level like data.synthetic's stack programs (see there)
+# repro-lint: disable=R1
+@functools.partial(jax.jit, static_argnums=1)
+def _pad_steps(leaves, H_max: int):
+    """One client's (H^k, ...) leaves zero-padded to (H_max, ...) on the
+    device: keyed by H^k, so at most H_max programs."""
+    return [jnp.concatenate(
+        [l, jnp.zeros((H_max - l.shape[0],) + l.shape[1:], l.dtype)])
+        for l in leaves]
 
 
 def pad_client_batches(client_batch_stacks: Sequence[Any],
@@ -112,6 +128,12 @@ def pad_client_batches(client_batch_stacks: Sequence[Any],
     batch to take shapes from. Trailing (per-batch) shapes and dtypes must
     agree across clients; raises ValueError otherwise — that raggedness
     needs the per-client fallback, not padding.
+
+    The stack is assembled on the device: host leaves go there as they
+    are, a short client is padded by one program per H^k (``_pad_steps``),
+    an empty one takes the shared device zero stack, and one program
+    keyed by (n_clients, H_max) stacks the clients. ``stacked`` holds
+    device arrays.
     """
     if not client_batch_stacks:
         raise ValueError("no client batch stacks")
@@ -119,22 +141,25 @@ def pad_client_batches(client_batch_stacks: Sequence[Any],
              int(jax.tree_util.tree_leaves(s)[0].shape[0])
              if jax.tree_util.tree_leaves(s) else 0)
             for s in client_batch_stacks]
-    ref = next((s for s, h in zip(client_batch_stacks, lens) if h), None)
-    if ref is None:
+    if not any(lens):
         raise ValueError("all clients empty; nothing to pad from")
     if H_max is None:
         H_max = max(lens)
     if max(lens) > H_max:
         raise ValueError(f"client iteration counts {lens} exceed "
                          f"H_max={H_max}")
-    ref_flat, treedef = jax.tree_util.tree_flatten(ref)
-    trailing = [(tuple(l.shape[1:]), np.asarray(l).dtype) for l in ref_flat]
 
     with obs.span("fed.pad"):
+        stacks = jax.device_put(list(client_batch_stacks))
+        ref = next(s for s, h in zip(stacks, lens) if h)
+        ref_flat, treedef = jax.tree_util.tree_flatten(ref)
+        # dtypes of the device leaves: canonical, and read without a copy
+        trailing = [(tuple(l.shape[1:]), l.dtype) for l in ref_flat]
         padded = []
-        for s, h in zip(client_batch_stacks, lens):
+        for s, h in zip(stacks, lens):
             if h == 0:
-                flat = [np.zeros((H_max,) + shp, dt) for shp, dt in trailing]
+                flat = [device_zeros((H_max,) + shp, dt)
+                        for shp, dt in trailing]
                 padded.append(jax.tree_util.tree_unflatten(treedef, flat))
                 continue
             if jax.tree_util.tree_structure(s) != treedef:
@@ -142,30 +167,19 @@ def pad_client_batches(client_batch_stacks: Sequence[Any],
                     "client batch stacks disagree on pytree structure "
                     "(keys); matching leaf shapes cannot substitute for "
                     "matching keys")
-            flat = [np.asarray(l) for l in jax.tree_util.tree_leaves(s)]
+            flat = jax.tree_util.tree_leaves(s)
             if [(tuple(l.shape[1:]), l.dtype) for l in flat] != trailing:
                 raise ValueError(
                     "client batch stacks disagree on per-batch "
                     "shapes/dtypes; padding only evens out iteration "
                     "counts — use the per-client fallback for truly ragged "
                     "batches")
-            pad = H_max - h
-            if pad:
-                flat = [np.concatenate(
-                    [l, np.zeros((pad,) + l.shape[1:], l.dtype)])
-                    for l in flat]
+            if h < H_max:
+                flat = _pad_steps(flat, H_max)
             padded.append(jax.tree_util.tree_unflatten(treedef, flat))
-        stacked = jax.tree_util.tree_map(
-            lambda *leaves: np.stack(leaves), *padded)
-        _count_staged(stacked)
+        stacked = stack_trees(padded)
+        count_stacked(stacked)
     return stacked, np.asarray(lens, np.int32)
-
-
-def _count_staged(tree) -> None:
-    """``staged_bytes``: the host bytes of a stack built for transfer."""
-    if obs.enabled():
-        obs.count("staged_bytes", sum(
-            l.nbytes for l in jax.tree_util.tree_leaves(tree)))
 
 
 def _count_clip_steps(stacked, iters=None, clients: bool = True) -> None:
@@ -724,14 +738,16 @@ class ShardedSyncRound(SyncRound):
         pad = (-n) % n_shards
         if pad:                  # zero-weight dummies round the axis up
             client_stacks = jax.tree_util.tree_map(
-                lambda l: np.concatenate(
-                    [np.asarray(l)] + [np.asarray(l[:1])] * pad),
+                lambda l: jnp.concatenate([l] + [l[:1]] * pad),
                 client_stacks)
             weights = jnp.concatenate(
                 [weights, jnp.zeros((pad,), jnp.float32)])
             iters = np.concatenate([iters, np.zeros((pad,), np.int32)])
             states = jax.tree_util.tree_map(
                 lambda l: jnp.concatenate([l] + [l[:1]] * pad), states)
+        # the stack was assembled on one device: lay it over the mesh
+        client_stacks = jax.device_put(
+            client_stacks, NamedSharding(self.mesh, self._specs["clients"]))
         _count_clip_steps(client_stacks, iters)
         out = self._jits.call(
             "shard", self._sharded_rnd,

@@ -30,7 +30,8 @@ import jax.numpy as jnp
 from repro import obs
 from repro.core import algorithms, compile_cache, fed_engine
 from repro.core.fedasync import cached_client_step, make_client_step
-from repro.data.synthetic import stack_batches
+from repro.data.synthetic import (count_stacked, device_zeros, stack_batches,
+                                  stack_rows)
 from repro.optim import trainable_mask
 from repro.types import FedConfig, ModelConfig
 
@@ -55,9 +56,10 @@ def weighted_average(param_trees: Sequence, weights: jax.Array):
 
 
 def _client_weights(n: int, data_sizes: Sequence[int] | None):
+    # built on the host and handed over explicitly (no implicit transfer)
     if data_sizes is None:
-        return jnp.full((n,), 1.0 / n, jnp.float32)
-    s = jnp.asarray(data_sizes, jnp.float32)
+        return jnp.asarray(np.full((n,), 1.0 / n, np.float32))
+    s = jnp.asarray(np.asarray(data_sizes, np.float32))
     return s / jnp.sum(s)
 
 
@@ -137,15 +139,11 @@ def fedavg_round(params_global, client_batches: Sequence, cfg: ModelConfig,
     counts = [len(bl) for bl in client_lists]
     if client_lists and len(sigs) == 1:
         if min(counts) == max(counts) > 0:
-            # stack straight to (n_clients, H, ...) — one host copy, not
-            # a per-client stack followed by a cross-client restack
-            keys = list(client_lists[0][0])
+            # stack straight to (n_clients, H, ...) on the device — one
+            # program, not a per-client stack followed by a restack
             with obs.span("fed.pad"):
-                stacked_clients = {
-                    k: np.stack([[b[k] for b in bl] for bl in client_lists])
-                    for k in keys}
-                obs.count("staged_bytes", sum(
-                    v.nbytes for v in stacked_clients.values()))
+                stacked_clients = stack_rows(jax.device_put(client_lists))
+                count_stacked(stacked_clients)
             if engine is None:
                 engine = fed_engine.make_sync_round(cfg, fed,
                                                     algorithm=algorithm)
@@ -167,7 +165,9 @@ def fedavg_round(params_global, client_batches: Sequence, cfg: ModelConfig,
 
 
 def _batch_sig(b):
-    return tuple(sorted((k, np.shape(v), str(np.asarray(v).dtype))
+    # ``v.dtype``, not ``np.asarray(v).dtype``: on a device leaf the
+    # latter is a hidden device-to-host copy
+    return tuple(sorted((k, np.shape(v), str(v.dtype))
                         for k, v in b.items()))
 
 
@@ -176,27 +176,28 @@ def _padded_round(params_global, client_lists, cfg, fed, engine, mask,
                   client_ids=None):
     """Heterogeneous-H round as one padded masked-scan program.
 
-    Batches write straight into one zero-initialized (n_clients, H_max,
-    ...) array per key — a single host copy, mirroring the homogeneous
-    branch — and the engine threads the true H^k vector through the scan
-    mask: one compiled program per round shape, whatever the H^k draw.
-    Empty clients run zero iterations and contribute the unchanged global
-    to the weighted average, matching the loop oracle. Zero pad rows are
-    what the mask discards, so their contents never matter.
+    The (n_clients, H_max, ...) stack is assembled on the device, mirroring
+    the homogeneous branch: every real batch goes to the device as it is,
+    each pad slot takes the one shared device zero batch of its shape, and
+    one program keyed by (n_clients, H_max) and the batch shapes stacks the
+    slots. The engine threads the true H^k vector through the scan mask:
+    one compiled program per round shape, whatever the H^k draw, for the
+    stack as for the round. Empty clients run zero iterations and
+    contribute the unchanged global to the weighted average, matching the
+    loop oracle. Zero pad rows are what the mask discards, so their
+    contents never matter.
     """
-    ref = next(b for bl in client_lists for b in bl)
     n = len(client_lists)
     H_max = max(fed.local_iters_max, max(len(bl) for bl in client_lists))
     iters = np.asarray([len(bl) for bl in client_lists], np.int32)
-    stacked = {}
     with obs.span("fed.pad"):
-        for k, v in ref.items():
-            out = np.zeros((n, H_max) + np.shape(v), np.asarray(v).dtype)
-            for c, bl in enumerate(client_lists):
-                for i, b in enumerate(bl):
-                    out[c, i] = b[k]
-            stacked[k] = out
-            obs.count("staged_bytes", out.nbytes)
+        client_lists = jax.device_put(client_lists)
+        ref = next(b for bl in client_lists for b in bl)
+        zero = jax.tree_util.tree_map(
+            lambda v: device_zeros(v.shape, v.dtype), ref)
+        stacked = stack_rows([bl + [zero] * (H_max - len(bl))
+                              for bl in client_lists])
+        count_stacked(stacked)
     if engine is None:
         engine = fed_engine.make_sync_round(cfg, fed, algorithm=algorithm)
     weights = _client_weights(n, data_sizes)

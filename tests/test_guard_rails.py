@@ -117,6 +117,40 @@ def test_scaffold_padded_round_steady_state(guard_rails, compile_budget):
     assert run.num_compiled == 1
 
 
+def test_padded_round_from_host_batches_no_implicit_transfers(
+        guard_rails, compile_budget):
+    """A sync round fed host batches assembles its padded stack on the
+    device: each batch goes over by an explicit ``device_put`` and the
+    pad slots share one device zero, so after one warm-up a new H^k draw
+    runs with zero new programs (the round's and the stack's) and zero
+    implicit host->device transfers."""
+    from repro.core import fedavg
+    from repro.data import synthetic
+    fed = FedConfig(num_clients=3, global_epochs=2, local_iters_min=1,
+                    local_iters_max=3, lr=0.01)
+    ds = SyntheticLMDataset(vocab=TINY.vocab_size, seq_len=8, seed=0)
+    params = registry.init_params(jax.random.PRNGKey(0), TINY)
+    engine = fed_engine.SyncRound(TINY, fed)   # private: isolate counts
+    mask = jax.tree_util.tree_map(
+        lambda _: jnp.asarray(1.0, jnp.float32), params)
+
+    def round_(Hs, seed0):
+        blists = [list(ds.batches(2, h, seed=seed0 + i))
+                  for i, h in enumerate(Hs)]
+        return fedavg.fedavg_round(params, [iter(b) for b in blists], TINY,
+                                   fed, engine=engine, mask=mask)
+
+    with compile_budget(engine, 1, exact=True):
+        round_([3, 1, 2], 10)
+    stacks = synthetic.stack_rows._cache_size()
+    for k, Hs in enumerate(([1, 2, 1], [2, 0, 3])):
+        with guard_rails(), compile_budget(engine, 0, exact=True):
+            _, losses = round_(Hs, 40 + 10 * k)
+        assert [len(l) for l in losses] == Hs
+        assert all(np.isfinite(l).all() for l in losses)
+    assert synthetic.stack_rows._cache_size() == stacks
+
+
 def test_serving_ladder_steady_state_no_compiles(guard_rails,
                                                  compile_budget, rng):
     """PR-5 invariant: decode programs are bounded by the bucket ladder,

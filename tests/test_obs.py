@@ -131,8 +131,11 @@ def test_staged_bytes_of_a_stack(recorder, inputs, build, span):
     recorder.enable()
     stacked = build(args)
     rec = recorder.disable()
-    assert rec["counts"] == {"staged_bytes": sum(
+    # assembled on the device: no host bytes staged
+    assert rec["counts"] == {"staged_bytes": 0, "device_stacked_bytes": sum(
         l.nbytes for l in jax.tree_util.tree_leaves(stacked))}
+    assert all(isinstance(l, jax.Array)
+               for l in jax.tree_util.tree_leaves(stacked))
     assert _names(rec) == [span]
 
 
@@ -175,8 +178,10 @@ def test_run_sync_counts_useful_and_executed_steps(recorder):
     assert c["clip_steps_useful"] == rounds * sum(iters) * BATCH
     assert (c["clip_steps_useful"] / c["clip_steps_executed"]
             == sum(iters) / (n * h_max))
-    # tokens and labels, (n, H_max, batch, seq) int32 each, every round
-    assert c["staged_bytes"] == rounds * 2 * n * h_max * BATCH * 8 * 4
+    # tokens and labels, (n, H_max, batch, seq) int32 each, every round,
+    # pad slots included, all assembled on the device
+    assert c["device_stacked_bytes"] == rounds * 2 * n * h_max * BATCH * 8 * 4
+    assert c["staged_bytes"] == 0
     names = _names(rec)
     assert names.count("sim.round") == rounds
     assert names.count("fed.pad") == names.count("fed.readback") == rounds
